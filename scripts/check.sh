@@ -16,6 +16,9 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
+# tests run on the CPU; tests/test_tpu_compile.py compiles for a
+# described TPU v5e through libtpu's compiler without needing a chip
+export JAX_PLATFORMS=cpu
 
 echo "== repo hygiene (repro.lint RH001-RH005) =="
 # tracked .pyc, stray bench/smoke JSON outside BENCH_*.json, the
@@ -32,11 +35,11 @@ echo "== contract lint (repro.lint RL001-RL007) =="
 # (docs/LINT.md).
 python -m repro.lint src tests benchmarks
 
-# tier-1 passed-count baseline as of PR 10 (PR 9: 415; PR 8: 383; PR 7:
-# 352; PR 6: 318; PR 5: 280; PR 4: 255; PR 3: 237; PR 2: 208; PR 1:
-# 143; seed: 36).  Bump this when a PR adds tests — it is what catches
-# silently lost/uncollected files, not just failures.
-BASELINE=447
+# tier-1 passed-count baseline.  Bump this when tests are added — it is
+# what catches silently lost/uncollected files, not just failures.  The
+# 54 v5e compile tests (tests/test_tpu_compile.py) skip without libtpu,
+# which requirements.txt brings through jax[tpu].
+BASELINE=501
 # tests carrying @pytest.mark.spmd (registered in pytest.ini): the
 # multi-device subprocess tests the fast lane deselects.
 SPMD_COUNT=9

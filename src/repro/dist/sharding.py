@@ -25,8 +25,6 @@ from contextlib import contextmanager
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.dist import compat  # noqa: F401  (installs jax 0.4.x shims)
-
 __all__ = [
     "make_rules",
     "strip_rules",
@@ -88,10 +86,9 @@ _CTX = _Ctx()
 def use_mesh(mesh, rules: dict, *, manual: bool = False):
     """Install (mesh, rules) as the ambient sharding context.
 
-    ``manual=True`` marks a partial-manual (shard_map) region: ``shard``
-    becomes the identity inside it — on jax 0.4.x the SPMD partitioner
-    rejects auto-axis constraints under a manual subgroup, and they are
-    layout hints, not semantics.
+    ``manual=True`` marks a shard_map region: ``shard`` becomes the
+    identity inside it — its constraints are layout hints, not
+    semantics.
     """
     _CTX.stack.append((mesh, dict(rules), manual))
     try:

@@ -23,6 +23,7 @@ import argparse
 import json
 import os
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -38,8 +39,21 @@ from repro.models.params import count_params
 from repro.train.state import init_train_state
 from repro.train.trainer import TrainConfig, make_coded_train_step, make_train_step
 
+REPO_ROOT = Path(__file__).resolve().parents[3]
 
-def main():
+
+def use_compile_cache() -> None:
+    """Keep JAX's persistent compile cache at ``<repo>/.jax_cache``.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    this sets nothing.  The path is fixed: it is part of the cache key.
+    """
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(REPO_ROOT / ".jax_cache"))
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gc-lm-110m")
     ap.add_argument("--reduced", action="store_true")
@@ -78,8 +92,12 @@ def main():
                     help="erasure-code checkpoints across the workers with S "
                          "parity shards (any workers-S survivors restore "
                          "bit-exactly; 0: monolithic npz)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def setup(args):
+    """(cfg, mesh, env, cfg_t, data) from the CLI args; ``--env`` also
+    sets ``args.workers`` to the loaded population's size."""
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -95,6 +113,56 @@ def main():
                         total_steps=args.steps)
     data = SyntheticTokens(DataConfig(vocab=cfg.vocab, seq_len=args.seq,
                                       global_batch=args.global_batch))
+    return cfg, mesh, env, cfg_t, data
+
+
+def coded_setup(args, cfg, cfg_t, mesh, env, params):
+    """The coded loop's plan, mode and step factory.
+
+    Returns ``(plan, mode, step_for)``: ``step_for(plan)`` is the jitted
+    coded step ``(state, worker_batches, dec_w) -> (state, metrics)``,
+    compiled once per partition.  Mode is ``spmd`` when every worker
+    has its own data rank, else ``sim`` (all workers on one device).
+    """
+    reduce_mode, grad_dtype, pipeline = "psum", None, "auto"
+    if args.autotune or args.hbm_gb or args.scheme == "auto":
+        from repro.tune import MemBudget, autotune
+
+        budget = (MemBudget.from_gb(args.hbm_gb)
+                  if args.hbm_gb else None)
+        res = autotune(cfg, env, budget,
+                       global_batch=args.global_batch,
+                       seq_len=args.seq)
+        plan, best = res.plan, res.best
+        reduce_mode, pipeline = best.reduce_mode, best.pipeline
+        grad_dtype = jnp.bfloat16 if best.grad_dtype == "bf16" else None
+        print(f"autotune: {len(res.report.candidates)} admissible, "
+              f"{len(res.report.pruned)} pruned "
+              f"(budget {budget or 'uncapped'})")
+        print(res.report.table())
+        print(f"selected {best.label()}")
+    else:
+        plan = Plan.build(params, env, scheme=args.scheme)
+    mode = "spmd" if args.data_par == args.workers else "sim"
+    step_mesh = mesh if mode == "spmd" else None
+    step_cache = {}
+
+    def step_for(p):
+        key = p.partition_key()
+        if key not in step_cache:
+            step_cache[key] = jax.jit(make_coded_train_step(
+                cfg, cfg_t, p, mesh=step_mesh, mode=mode,
+                reduce_mode=reduce_mode, grad_dtype=grad_dtype,
+                pipeline=pipeline))
+        return step_cache[key]
+
+    return plan, mode, step_for
+
+
+def main():
+    args = parse_args()
+    use_compile_cache()
+    cfg, mesh, env, cfg_t, data = setup(args)
 
     manager = None
     if args.ckpt:
@@ -125,39 +193,9 @@ def main():
                     print(f"step {i:4d} loss {float(metrics['loss']):.4f} "
                           f"({time.perf_counter()-t0:.2f}s)")
         else:
-            reduce_mode, grad_dtype, pipeline = "psum", None, "auto"
-            if args.autotune or args.hbm_gb or args.scheme == "auto":
-                from repro.tune import MemBudget, autotune
-
-                budget = (MemBudget.from_gb(args.hbm_gb)
-                          if args.hbm_gb else None)
-                res = autotune(cfg, env, budget,
-                               global_batch=args.global_batch,
-                               seq_len=args.seq)
-                plan, best = res.plan, res.best
-                reduce_mode, pipeline = best.reduce_mode, best.pipeline
-                grad_dtype = jnp.bfloat16 if best.grad_dtype == "bf16" else None
-                print(f"autotune: {len(res.report.candidates)} admissible, "
-                      f"{len(res.report.pruned)} pruned "
-                      f"(budget {budget or 'uncapped'})")
-                print(res.report.table())
-                print(f"selected {best.label()}")
-            else:
-                plan = Plan.build(state.params, env, scheme=args.scheme)
+            plan, mode, step_for = coded_setup(args, cfg, cfg_t, mesh, env,
+                                               state.params)
             sim = plan.simulator(env)
-            mode = "spmd" if args.data_par == args.workers else "sim"
-            step_mesh = mesh if mode == "spmd" else None
-            step_cache = {}
-
-            def step_for(p):
-                key = p.partition_key()
-                if key not in step_cache:
-                    step_cache[key] = jax.jit(make_coded_train_step(
-                        cfg, cfg_t, p, mesh=step_mesh, mode=mode,
-                        reduce_mode=reduce_mode, grad_dtype=grad_dtype,
-                        pipeline=pipeline))
-                return step_cache[key]
-
             step = step_for(plan)
             controller = None
             if args.adapt:

@@ -53,22 +53,6 @@ def _capacity(n_tokens: int, moe) -> int:
     return max(8, -(-cap // 8) * 8)  # multiple of 8 for layout sanity
 
 
-def _top_k(x, k: int):
-    """k successive argmaxes — identical (values, indices) to
-    ``jax.lax.top_k`` incl. tie order, but lowers to reductions instead
-    of a sort, which the SPMD partitioner accepts inside the manual
-    shard_map subgroup (sort-based top_k aborts it on jax 0.4.x)."""
-    rows = jnp.arange(x.shape[0])
-    vals, idxs = [], []
-    work = x
-    for _ in range(k):
-        i = jnp.argmax(work, axis=-1)
-        vals.append(jnp.take_along_axis(work, i[:, None], axis=-1)[:, 0])
-        idxs.append(i)
-        work = work.at[rows, i].set(-jnp.inf)
-    return jnp.stack(vals, -1), jnp.stack(idxs, -1)
-
-
 def apply_moe(cfg, p, x, spec):
     """x: (B, S, d) -> (out, aux_loss).  Dispatches to the GSPMD path or
     the manual shard_map path per cfg.moe_impl."""
@@ -137,12 +121,12 @@ def _moe_core(cfg, p, x, spec):
     logits = jnp.einsum("td,de->te", xt, p["router"].astype(dt)).astype(jnp.float32)
     if moe.router == "sigmoid":
         scores = jax.nn.sigmoid(logits)
-        gate_vals, idx = _top_k(scores, k)  # (t, k)
+        gate_vals, idx = jax.lax.top_k(scores, k)  # (t, k)
         gates = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
         probs = scores / jnp.maximum(scores.sum(-1, keepdims=True), 1e-9)
     else:
         probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, idx = _top_k(probs, k)
+        gate_vals, idx = jax.lax.top_k(probs, k)
         gates = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
 
     # ---- aux load-balance loss (switch-style): E * sum_e f_e * P_e

@@ -38,8 +38,8 @@ is the jax integration:
     ``DeprecationWarning`` pointing at ``combine_grads``.
 
 Two execution modes share the math:
-  * ``mode='spmd'``  — jax.shard_map over the mesh 'data' axis (manual),
-                       other axes (model/pod) remain GSPMD-auto: the
+  * ``mode='spmd'``  — jax.shard_map, manual over every mesh axis:
+                       coding runs across the 'data' ranks and the
                        decoded gradient materializes as a weighted psum.
   * ``mode='sim'``   — single-device simulation: lax.map over workers
                        (examples, CPU tests).
@@ -453,33 +453,26 @@ def make_coded_grad_fn(cfg, plan: CodingPlan, *, mesh=None, data_axis: str = "da
 
         return grad_fn
 
-    # ---- spmd: manual over the data axis (and the pod axis when present:
-    # coding runs across data-parallel ranks, plain summation across pods;
-    # keeping the pod axis manual also keeps all token gathers local,
-    # which sidesteps an XLA partial-manual PartitionGather abort).
+    # ---- spmd: coding runs across the data-parallel ranks, plain
+    # summation across pods.  The region is manual over EVERY mesh axis:
+    # XLA's SPMD partitioner aborts the process (``Invalid binary
+    # instruction opcode copy``) partitioning this backward under a
+    # partial-manual subgroup (data manual, model auto).  Axes beyond
+    # data/pod therefore carry replicated copies inside the coded region
+    # (no tensor parallelism there) — numerically identical.
     assert mesh is not None
-    from repro.dist.compat import IS_LEGACY_JAX
-    from repro.dist.sharding import current_rules, make_rules, strip_rules, use_mesh
+    from repro.dist.sharding import use_mesh
 
     extra_axes = tuple(a for a in ("pod",) if a in mesh.shape)
-    manual_axes = {data_axis, *extra_axes}
-    if IS_LEGACY_JAX:
-        # jax 0.4.x XLA aborts on sort/gather HLOs under a *partial*
-        # manual subgroup; go fully manual instead.  Axes beyond
-        # data/pod then carry replicated copies inside the coded region
-        # (no tensor parallelism there) — numerically identical.
-        manual_axes = set(mesh.shape)
     extra_size = 1
     for a in extra_axes:
         extra_size *= mesh.shape[a]
-    inner_rules = strip_rules(make_rules(cfg), manual_axes)
     denom = n_workers * extra_size
 
     if pipeline == "flat":
         return _make_flat_spmd_grad_fn(
             cfg, layout, b_rows, n_workers, mesh=mesh, data_axis=data_axis,
-            extra_axes=extra_axes, manual_axes=manual_axes,
-            inner_rules=inner_rules, denom=denom, reduce_mode=reduce_mode,
+            extra_axes=extra_axes, denom=denom, reduce_mode=reduce_mode,
             grad_dtype=grad_dtype)
 
     scatter = None
@@ -525,9 +518,7 @@ def make_coded_grad_fn(cfg, plan: CodingPlan, *, mesh=None, data_axis: str = "da
 
     def manual_fn(params, my_batches, dec_w, my_rows, my_aux=None):
         # my_batches: (1, K, rows/P, S+1); my_rows: (1, n_used, K)
-        # inside the manual region, sharding constraints may only use
-        # the remaining auto axes — reinstall stripped rules.
-        with use_mesh(mesh, inner_rules, manual=True):
+        with use_mesh(mesh, {}, manual=True):
             rank = jax.lax.axis_index(data_axis)
             aux0 = None if my_aux is None else my_aux[0]
             g = _per_shard_grads(cfg, params, my_batches[0], aux0)
@@ -543,7 +534,6 @@ def make_coded_grad_fn(cfg, plan: CodingPlan, *, mesh=None, data_axis: str = "da
                 mesh=mesh,
                 in_specs=(P(), batch_spec, P(), P(data_axis)),
                 out_specs=out_specs,
-                axis_names=manual_axes,
                 check_vma=False,
             )
             return smapped(params, worker_batches, dec_w, b_rows)
@@ -552,7 +542,6 @@ def make_coded_grad_fn(cfg, plan: CodingPlan, *, mesh=None, data_axis: str = "da
             mesh=mesh,
             in_specs=(P(), batch_spec, P(), P(data_axis), batch_spec),
             out_specs=out_specs,
-            axis_names=manual_axes,
             check_vma=False,
         )
         return smapped(params, worker_batches, dec_w, b_rows, worker_aux)
@@ -561,8 +550,8 @@ def make_coded_grad_fn(cfg, plan: CodingPlan, *, mesh=None, data_axis: str = "da
 
 
 def _make_flat_spmd_grad_fn(cfg, layout, b_rows, n_workers, *, mesh,
-                            data_axis, extra_axes, manual_axes, inner_rules,
-                            denom, reduce_mode, grad_dtype) -> Callable:
+                            data_axis, extra_axes, denom, reduce_mode,
+                            grad_dtype) -> Callable:
     """The flat fused spmd path: each rank streams its per-shard grads
     through the fused encode⊙decode matmul into the plan's packed
     per-level buffers, the reduction is ONE collective per level over
@@ -580,7 +569,7 @@ def _make_flat_spmd_grad_fn(cfg, layout, b_rows, n_workers, *, mesh,
     batch_spec = P(data_axis, None, extra_axes if extra_axes else None)
 
     def manual_fn(params, my_batches, dec_w, my_rows, my_aux=None):
-        with use_mesh(mesh, inner_rules, manual=True):
+        with use_mesh(mesh, {}, manual=True):
             rank = jax.lax.axis_index(data_axis)
             aux0 = None if my_aux is None else my_aux[0]
             g = _per_shard_grads(cfg, params, my_batches[0], aux0)
@@ -604,7 +593,6 @@ def _make_flat_spmd_grad_fn(cfg, layout, b_rows, n_workers, *, mesh,
                 mesh=mesh,
                 in_specs=(P(), batch_spec, P(), P(data_axis)),
                 out_specs=buf_specs,
-                axis_names=manual_axes,
                 check_vma=False,
             )
             bufs = smapped(params, worker_batches, dec_w, b_rows)
@@ -614,7 +602,6 @@ def _make_flat_spmd_grad_fn(cfg, layout, b_rows, n_workers, *, mesh,
                 mesh=mesh,
                 in_specs=(P(), batch_spec, P(), P(data_axis), batch_spec),
                 out_specs=buf_specs,
-                axis_names=manual_axes,
                 check_vma=False,
             )
             bufs = smapped(params, worker_batches, dec_w, b_rows, worker_aux)
