@@ -39,7 +39,7 @@ python -m repro.lint src tests benchmarks
 # what catches silently lost/uncollected files, not just failures.  The
 # 54 v5e compile tests (tests/test_tpu_compile.py) skip without libtpu,
 # which requirements.txt brings through jax[tpu].
-BASELINE=501
+BASELINE=537
 # tests carrying @pytest.mark.spmd (registered in pytest.ini): the
 # multi-device subprocess tests the fast lane deselects.
 SPMD_COUNT=9

@@ -11,6 +11,12 @@ trainer (paper technique; straggler realizations simulated host-side)
 or the plain pjit baseline.  On a TPU slice the same entry point scales
 to the production meshes in launch/mesh.py.
 
+Each step is a ``StepTraceAnnotation("train")`` holding host spans
+(``batch_build``, ``straggler_draw``, ``dispatch``, ``wait``,
+``metrics_sync``, ``ckpt_save``, ``replan``); a profiler session
+(``jax.profiler.start_server`` or ``trace``) puts them on the device
+trace's clock (docs/PERF.md, "Profiling a run").
+
 The straggler environment is ``Env.iid(ShiftedExponential(mu), N)`` by
 default; ``--env`` loads a full worker-population model (heterogeneous
 per-worker distributions, degradations, traces) from an
@@ -22,7 +28,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import time
 from pathlib import Path
 
 import jax
@@ -181,17 +186,26 @@ def main():
                 state, resumed = restored
                 print(f"resumed from checkpoint step {resumed} "
                       f"under {args.ckpt}")
+        span = jax.profiler.TraceAnnotation
         if args.uncoded:
             step = jax.jit(make_train_step(cfg, cfg_t))
-            while (i := int(state.step)) < args.steps:
-                batch = {"tokens": jnp.asarray(data.batch(i))}
-                t0 = time.perf_counter()
-                state, metrics = step(state, batch)
-                if manager is not None:
-                    manager.maybe_save(int(state.step), state)
+            i = int(state.step)
+            while i < args.steps:
+                with jax.profiler.StepTraceAnnotation("train", step_num=i):
+                    with span("batch_build"):
+                        batch = {"tokens": jnp.asarray(data.batch(i))}
+                    with span("dispatch"):
+                        state, metrics = step(state, batch)
+                    with span("wait"):
+                        jax.block_until_ready((state, metrics))
+                    with span("metrics_sync"):
+                        done, loss = int(state.step), float(metrics["loss"])
+                    if manager is not None:
+                        with span("ckpt_save"):
+                            manager.maybe_save(done, state)
                 if i % 10 == 0 or i == args.steps - 1:
-                    print(f"step {i:4d} loss {float(metrics['loss']):.4f} "
-                          f"({time.perf_counter()-t0:.2f}s)")
+                    print(f"step {i:4d} loss {loss:.4f}")
+                i = done
         else:
             plan, mode, step_for = coded_setup(args, cfg, cfg_t, mesh, env,
                                                state.params)
@@ -205,28 +219,39 @@ def main():
                     AdaptConfig(window=args.adapt_window), plan, state.params)
             print(f"plan x={plan.x.tolist()} s_max={plan.s_max} mode={mode} "
                   f"adapt={bool(controller)}")
-            while (i := int(state.step)) < args.steps:
-                wb = jnp.asarray(coded_worker_batches(data, i, args.workers,
-                                                      plan.s_max))
-                dec_w, rec = sim.step()
-                t0 = time.perf_counter()
-                state, metrics = step(state, wb, dec_w)
-                if manager is not None:
-                    manager.maybe_save(int(state.step), state,
-                                       extra={"plan": plan.to_dict()})
-                if controller is not None:
-                    new_plan = controller.observe(rec["times"])
-                    if new_plan is not None:
-                        plan, sim.plan = new_plan, new_plan
-                        step = step_for(new_plan)
-                        print(f"step {i:4d} plan swap -> x={plan.x.tolist()} "
-                              f"(predicted gain "
-                              f"{controller.swaps[-1].predicted_gain:.1%})")
+            i = int(state.step)
+            while i < args.steps:
+                with jax.profiler.StepTraceAnnotation("train", step_num=i):
+                    with span("batch_build"):
+                        wb = jnp.asarray(coded_worker_batches(
+                            data, i, args.workers, plan.s_max))
+                    with span("straggler_draw"):
+                        dec_w, rec = sim.step()
+                    with span("dispatch"):
+                        state, metrics = step(state, wb, dec_w)
+                    with span("wait"):
+                        jax.block_until_ready((state, metrics))
+                    with span("metrics_sync"):
+                        done, loss = int(state.step), float(metrics["loss"])
+                    if manager is not None:
+                        with span("ckpt_save"):
+                            manager.maybe_save(done, state,
+                                               extra={"plan": plan.to_dict()})
+                    if controller is not None:
+                        with span("replan"):
+                            new_plan = controller.observe(rec["times"])
+                            if new_plan is not None:
+                                plan, sim.plan = new_plan, new_plan
+                                step = step_for(new_plan)
+                                gain = controller.swaps[-1].predicted_gain
+                                print(f"step {i:4d} plan swap -> "
+                                      f"x={plan.x.tolist()} (predicted gain "
+                                      f"{gain:.1%})")
                 if i % 10 == 0 or i == args.steps - 1:
-                    print(f"step {i:4d} loss {float(metrics['loss']):.4f} "
+                    print(f"step {i:4d} loss {loss:.4f} "
                           f"tau_c {rec['tau_coded']:.3g} "
-                          f"tau_u {rec['tau_uncoded']:.3g} "
-                          f"({time.perf_counter()-t0:.2f}s)")
+                          f"tau_u {rec['tau_uncoded']:.3g}")
+                i = done
             print("ledger:", json.dumps(sim.summary()))
             if controller is not None:
                 print(f"adaptive: {len(controller.swaps)} plan swap(s), "

@@ -6,8 +6,9 @@ output format, no bash/python drift:
 
   * RH001 — tracked ``.pyc`` files (43 of them shipped before PR 3's
     cleanup; a tracked bytecode file silently shadows source edits).
-  * RH002 — tracked bench/smoke JSON outside ``BENCH_*.json``:
-    committed perf rows live in ``BENCH_*.json`` only; per-run dumps
+  * RH002 — tracked bench/smoke JSON outside ``BENCH_*.json`` and the
+    chip benchmark's ``BENCHMARK.json``: committed perf rows live in
+    ``BENCH_*.json`` only; per-run dumps
     (``bench_smoke.json``, scratch output) belong in .gitignore — a
     tracked one silently goes stale and reads as current.
   * RH003 — the committed ``BENCH_async.json`` headline must stay at
@@ -54,7 +55,7 @@ def ckpt_overhead_floor(n_shards: int, parity: int) -> float:
     return 1.5 * (parity / n_shards + 1.0)
 
 _BENCHISH = re.compile(r"(bench|smoke)", re.IGNORECASE)
-_COMMITTED = re.compile(r"^BENCH_[A-Za-z0-9_]+\.json$")
+_COMMITTED = re.compile(r"^(BENCH_[A-Za-z0-9_]+|BENCHMARK)\.json$")
 
 
 def _repo_root(start: Optional[Path] = None) -> Path:
@@ -89,7 +90,8 @@ def run_hygiene(root=None) -> List[Finding]:
                 and not _COMMITTED.match(name):
             findings.append(Finding(
                 "RH002", f, 0, 0,
-                "tracked bench/smoke artifact outside BENCH_*.json — "
+                "tracked bench/smoke artifact outside BENCH_*.json / "
+                "BENCHMARK.json — "
                 "git rm --cached it (per-run dumps go stale silently)"))
 
     async_json = root / "BENCH_async.json"

@@ -47,6 +47,15 @@ Two execution modes share the math:
 Exactness invariant (tested): for EVERY straggler realization, the
 decoded gradient equals the plain data-parallel gradient over the same
 global batch, to float tolerance — on both pipelines.
+
+Each layer of the step runs under a ``jax.named_scope``, which lands in
+the compiled HLO's ``op_name`` metadata and so names the layer of every
+device op in a profiler trace (docs/PERF.md, "Profiling a run"):
+``per_shard_grad`` (the K forward/backward passes), ``gc_pack`` (the
+stacks relaid out for the kernel and packed into the level buffers),
+``gc_combine`` (the encode⊙decode kernel), ``level_collective`` (the
+per-level reduction) and ``gc_unpack`` (level buffers back to leaves).
+Scopes are metadata only: no computed value depends on them.
 """
 from __future__ import annotations
 
@@ -177,7 +186,8 @@ def _per_shard_grads(cfg, params, shards_tokens, shards_aux=None):
         loss_fn = lambda p: train_loss(cfg, p, batch)[0]
         return jax.grad(loss_fn)(params)
 
-    return jax.lax.map(one, (shards_tokens, shards_aux))
+    with jax.named_scope("per_shard_grad"):
+        return jax.lax.map(one, (shards_tokens, shards_aux))
 
 
 # ------------------------------------------------- tree combine (baseline)
@@ -219,7 +229,9 @@ def _fused_level_leaves(layout, leaves_nk, b_rows, dec_w_row, li, n_workers,
     for j in layout.level_leaves[li]:
         shape = layout.leaf_shapes[j]
         g = leaves_nk[j].reshape((w.shape[1], -1))                  # (N*K, sz)
-        y = ops.encode_decode(inv_n, w, g)[0].reshape(shape)
+        with jax.named_scope("gc_combine"):
+            y = ops.encode_decode(inv_n, w, g)[0]
+        y = y.reshape(shape)
         if grad_dtype is not None:
             y = y.astype(grad_dtype)
         out[j] = y
@@ -276,6 +288,10 @@ def _fused_rank_levels(layout, leaves_k, rows_rank, dec_w_rank, denom,
     (lane-aligned, N-divisible zero tail), ready for one psum /
     psum_scatter per level.  bf16 ``grad_dtype`` is applied to the
     packed buffer, halving the collective bytes.
+
+    Named scopes: ``gc_combine`` is the kernel call alone; the relayout
+    of each stack into the kernel's (K, size) operand, the zero tail,
+    the concatenation and the cast are ``gc_pack``.
     """
     bufs = []
     for li in range(layout.n_levels):
@@ -283,14 +299,17 @@ def _fused_rank_levels(layout, leaves_k, rows_rank, dec_w_rank, denom,
         row = rows_rank[li][None, :]         # (1, K) coding row
         parts = []
         for j in layout.level_leaves[li]:
-            g = leaves_k[j].reshape((row.shape[1], -1))  # (K, size)
-            parts.append(ops.encode_decode(a, row, g)[0])
-        pad = layout.level_sizes[li] - layout.level_used[li]
-        if pad:
-            parts.append(jnp.zeros((pad,), parts[0].dtype))
-        buf = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
-        if grad_dtype is not None:
-            buf = buf.astype(grad_dtype)
+            with jax.named_scope("gc_pack"):
+                g = leaves_k[j].reshape((row.shape[1], -1))  # (K, size)
+            with jax.named_scope("gc_combine"):
+                parts.append(ops.encode_decode(a, row, g)[0])
+        with jax.named_scope("gc_pack"):
+            pad = layout.level_sizes[li] - layout.level_used[li]
+            if pad:
+                parts.append(jnp.zeros((pad,), parts[0].dtype))
+            buf = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+            if grad_dtype is not None:
+                buf = buf.astype(grad_dtype)
         bufs.append(buf)
     return bufs
 
@@ -324,8 +343,9 @@ def combine_grads(plan: Plan, grads_stacked, dec_w, *, pipeline: str = "flat",
 
     def worker(n):
         per_worker = treedef.unflatten([l[n] for l in leaves])
-        c = _tree_encode(per_worker, b_rows[n], level_idx)
-        c = _tree_scale(c, dec_w[:, n], level_idx)
+        with jax.named_scope("gc_combine"):
+            c = _tree_encode(per_worker, b_rows[n], level_idx)
+            c = _tree_scale(c, dec_w[:, n], level_idx)
         if grad_dtype is not None:  # mirror the spmd reduce: cast, then sum
             c = jax.tree.map(lambda l: l.astype(grad_dtype), c)
         return c
@@ -444,8 +464,9 @@ def make_coded_grad_fn(cfg, plan: CodingPlan, *, mesh=None, data_axis: str = "da
             def worker(n):
                 aux_n = None if worker_aux is None else worker_aux[n]
                 g = _per_shard_grads(cfg, params, worker_batches[n], aux_n)
-                c = _tree_encode(g, b_rows[n], level_idx)
-                return _tree_scale(c, dec_w[:, n], level_idx)
+                with jax.named_scope("gc_combine"):
+                    c = _tree_encode(g, b_rows[n], level_idx)
+                    return _tree_scale(c, dec_w[:, n], level_idx)
 
             contribs = jax.lax.map(worker, jnp.arange(n_workers))
             summed = jax.tree.map(lambda l: l.sum(0), contribs)
@@ -493,6 +514,7 @@ def make_coded_grad_fn(cfg, plan: CodingPlan, *, mesh=None, data_axis: str = "da
                 specs.append(P(*entries))
         out_specs = jax.tree.unflatten(treedef, specs)
 
+    @jax.named_scope("level_collective")
     def _reduce(tree):
         if grad_dtype is not None:
             tree = jax.tree.map(lambda l: l.astype(grad_dtype), tree)
@@ -522,8 +544,9 @@ def make_coded_grad_fn(cfg, plan: CodingPlan, *, mesh=None, data_axis: str = "da
             rank = jax.lax.axis_index(data_axis)
             aux0 = None if my_aux is None else my_aux[0]
             g = _per_shard_grads(cfg, params, my_batches[0], aux0)
-            c = _tree_encode(g, my_rows[0], level_idx)
-            contrib = _tree_scale(c, dec_w[:, rank], level_idx)
+            with jax.named_scope("gc_combine"):
+                c = _tree_encode(g, my_rows[0], level_idx)
+                contrib = _tree_scale(c, dec_w[:, rank], level_idx)
             decoded = _reduce(contrib)
             return jax.tree.map(lambda l: l / denom, decoded)
 
@@ -576,13 +599,15 @@ def _make_flat_spmd_grad_fn(cfg, layout, b_rows, n_workers, *, mesh,
             leaves, _ = jax.tree.flatten(g)  # (K, *shape) each
             bufs = _fused_rank_levels(layout, leaves, my_rows[0],
                                       dec_w[:, rank], denom, grad_dtype)
-            if extra_axes:  # sum the pod halves of each shard first
-                bufs = list(jax.lax.psum(tuple(bufs), extra_axes))
-            if scatter:
-                return [jax.lax.psum_scatter(b, data_axis,
-                                             scatter_dimension=0, tiled=True)
-                        for b in bufs]
-            return list(jax.lax.psum(tuple(bufs), data_axis))
+            with jax.named_scope("level_collective"):
+                if extra_axes:  # sum the pod halves of each shard first
+                    bufs = list(jax.lax.psum(tuple(bufs), extra_axes))
+                if scatter:
+                    return [jax.lax.psum_scatter(b, data_axis,
+                                                 scatter_dimension=0,
+                                                 tiled=True)
+                            for b in bufs]
+                return list(jax.lax.psum(tuple(bufs), data_axis))
 
     def grad_fn(params, worker_batches, dec_w, worker_aux=None):
         treedef = jax.tree.structure(params)
@@ -607,7 +632,8 @@ def _make_flat_spmd_grad_fn(cfg, layout, b_rows, n_workers, *, mesh,
             bufs = smapped(params, worker_batches, dec_w, b_rows, worker_aux)
         # one unflatten into the optimizer (GSPMD re-shards sliced leaves
         # of scattered buffers as consumers demand)
-        return treedef.unflatten(layout.unpack(bufs))
+        with jax.named_scope("gc_unpack"):
+            return treedef.unflatten(layout.unpack(bufs))
 
     return grad_fn
 

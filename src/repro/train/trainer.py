@@ -10,10 +10,15 @@
                             step serves every realization.
 ``Trainer``               — loop: data, straggler sim, runtime ledger,
                             checkpointing, metrics.
+
+The step's ``monitor_forward`` and ``optimizer`` named scopes, with those
+of ``repro.train.coded``, name each device op's layer in a profiler
+trace; ``Trainer.run`` marks each step (``StepTraceAnnotation("train")``)
+and its host work (``TraceAnnotation`` spans) on the same clock.  See
+docs/PERF.md, "Profiling a run".
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from functools import partial
 from typing import Any, Callable, Optional
@@ -41,6 +46,7 @@ class TrainConfig:
     b2: float = 0.95
 
 
+@jax.named_scope("optimizer")
 def _apply_update(cfg_t: TrainConfig, state: TrainState, grads, metrics):
     lr = cosine_schedule(state.step, cfg_t.lr, cfg_t.warmup, cfg_t.total_steps)
     grads, gnorm = clip_by_global_norm(grads, cfg_t.clip_norm)
@@ -84,10 +90,11 @@ def make_coded_train_step(cfg, cfg_t: TrainConfig, plan: Plan, *,
     def step(state: TrainState, worker_batches, dec_w, worker_aux=None):
         grads = grad_fn(state.params, worker_batches, dec_w, worker_aux)
         # monitoring loss on shard 0 (cheap; the grads are what matter)
-        mon = {"tokens": worker_batches[0, 0]}
-        if worker_aux is not None:
-            mon["aux_inputs"] = worker_aux[0, 0]
-        loss, metrics = train_loss(cfg, state.params, mon)
+        with jax.named_scope("monitor_forward"):
+            mon = {"tokens": worker_batches[0, 0]}
+            if worker_aux is not None:
+                mon["aux_inputs"] = worker_aux[0, 0]
+            loss, metrics = train_loss(cfg, state.params, mon)
         return _apply_update(cfg_t, state, grads, metrics)
 
     return step
@@ -290,38 +297,60 @@ class Trainer:
                    f"from survivors @ step {ckpt_step}")
         return ev
 
+    def _after_round(self, rec, metrics, log_every, log_fn) -> int:
+        """Feed the round's realized times to the controller (swapping the
+        plan on drift) and the death watch (recovering from deaths);
+        returns the step to run next, which a recovery rewinds."""
+        if self.controller is not None:
+            new_plan = self.controller.observe(rec["times"])
+            if new_plan is not None:
+                self.swap_plan(new_plan)
+                metrics["plan_swap"] = 1
+                if log_every:
+                    log_fn(f"step {metrics['step']:5d}  plan swap -> "
+                           f"x={new_plan.x.tolist()} (predicted gain "
+                           f"{self.controller.swaps[-1].predicted_gain:.1%})")
+        if self.deathwatch is not None:
+            newly = self.deathwatch.observe(rec["times"])
+            if newly:
+                ev = self.recover_from_deaths(
+                    newly, log_fn if log_every else None)
+                if ev is not None:
+                    metrics["recovery"] = 1
+                    metrics["recovery_ckpt_step"] = ev.ckpt_step
+                    return int(self.state.step)
+        return metrics["step"]
+
     def run(self, n_steps: int, log_every: int = 10, log_fn=print):
         if self.wave is not None:
             return self.wave.run(n_steps, log_every, log_fn)
+        span = jax.profiler.TraceAnnotation
+        step = int(self.state.step)
         for i in range(n_steps):
-            wb = coded_worker_batches(self.data, int(self.state.step),
-                                      self.n_workers, self.plan.s_max)
-            dec_w, rec = self.sim.step()
-            t0 = time.perf_counter()
-            self.state, metrics = self.step_fn(self.state, jnp.asarray(wb), dec_w)
-            metrics = {k: float(v) for k, v in metrics.items()}
-            metrics.update(step=int(self.state.step), wall_s=time.perf_counter() - t0,
-                           tau_coded=rec["tau_coded"], tau_uncoded=rec["tau_uncoded"])
-            if self.controller is not None:
-                new_plan = self.controller.observe(rec["times"])
-                if new_plan is not None:
-                    self.swap_plan(new_plan)
-                    metrics["plan_swap"] = 1
-                    if log_every:
-                        log_fn(f"step {metrics['step']:5d}  plan swap -> "
-                               f"x={new_plan.x.tolist()} (predicted gain "
-                               f"{self.controller.swaps[-1].predicted_gain:.1%})")
-            if self.deathwatch is not None:
-                newly = self.deathwatch.observe(rec["times"])
-                if newly:
-                    ev = self.recover_from_deaths(
-                        newly, log_fn if log_every else None)
-                    if ev is not None:
-                        metrics["recovery"] = 1
-                        metrics["recovery_ckpt_step"] = ev.ckpt_step
-            if self.manager is not None:
-                self.manager.maybe_save(int(self.state.step), self.state,
-                                        extra={"plan": self.plan.to_dict()})
+            with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                with span("batch_build"):
+                    wb = jnp.asarray(coded_worker_batches(
+                        self.data, step, self.n_workers, self.plan.s_max))
+                with span("straggler_draw"):
+                    dec_w, rec = self.sim.step()
+                with span("dispatch"):
+                    self.state, metrics = self.step_fn(self.state, wb, dec_w)
+                with span("wait"):
+                    jax.block_until_ready((self.state, metrics))
+                with span("metrics_sync"):
+                    metrics = {k: float(v) for k, v in metrics.items()}
+                    step = int(self.state.step)
+                metrics.update(step=step, tau_coded=rec["tau_coded"],
+                               tau_uncoded=rec["tau_uncoded"])
+                if self.controller is not None or self.deathwatch is not None:
+                    with span("replan"):
+                        step = self._after_round(rec, metrics, log_every,
+                                                 log_fn)
+                if self.manager is not None:
+                    with span("ckpt_save"):
+                        self.manager.maybe_save(
+                            step, self.state,
+                            extra={"plan": self.plan.to_dict()})
             self.history.append(metrics)
             if log_every and (i % log_every == 0 or i == n_steps - 1):
                 log_fn(f"step {metrics['step']:5d}  loss {metrics['loss']:.4f}  "

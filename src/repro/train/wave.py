@@ -46,7 +46,6 @@ requeued, so the time stream stays aligned with the round index.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import Optional
 
@@ -338,7 +337,6 @@ class WaveRunner:
                 assert rd.decoded == n_used, (ev, rd.decoded, n_used)
                 dec_w = np.asarray(rd.dec_w, np.float32)
                 wb_j = jnp.asarray(rd.wb)
-                t0 = time.perf_counter()
                 if strategy == "barrier":
                     # the synchronous Trainer's own compiled step — the
                     # staleness-0 bit-identity guarantee
@@ -358,7 +356,6 @@ class WaveRunner:
                                             * plan.total_units)}
                 tr.sim.ledger.append(rec)
                 metrics.update(step=int(tr.state.step),
-                               wall_s=time.perf_counter() - t0,
                                tau_coded=rec["tau_coded"],
                                tau_uncoded=rec["tau_uncoded"],
                                staleness=(ev.round - 1) - rd.version)
