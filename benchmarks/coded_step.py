@@ -6,10 +6,10 @@ gradients with the real gc-lm-110m leaf structure, for both pipelines:
 
   * ``tree`` — the legacy per-leaf loop: lax.map over workers, per-leaf
     encode tensordot, per-leaf decode-weight scale, per-leaf sum, 1/N.
-  * ``flat`` — the fused pipeline: per leaf ONE skinny matmul
-    ``(dec_w ⊙ rows / N) @ G`` streaming the whole (N*K, size) shard
-    stack once (kernels/gc_fused math; ``Plan.flat_layout`` supplies
-    the leaf -> level binding).
+  * ``flat`` — the fused pipeline: per leaf ONE weighted sum
+    ``Σ (dec_w ⊙ rows / N) · g`` streaming the whole (N*K, *shape)
+    shard stack once (kernels/gc_fused math; ``Plan.flat_layout``
+    supplies the leaf -> level binding).
 
 Effective GB/s is the mandatory traffic N*K*D*4 bytes (every pipeline
 must read every per-shard gradient once) over wall time; the flat

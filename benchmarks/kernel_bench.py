@@ -3,7 +3,8 @@ and effective GB/s on this host (jnp oracle path — the TPU path is the
 Pallas kernel, validated in interpret mode by the test suite).
 
 ``gc_fused`` is the encode⊙decode single-pass combine the flat training
-pipeline runs (kernels/gc_fused); comparing its row against gc_encode +
+pipeline runs (kernels/gc_fused): the weighted sum of a (K, D) per-shard
+stack, as for a 1-D leaf; comparing its row against gc_encode +
 gc_decode at the same shape shows what the fusion saves.
 """
 from __future__ import annotations
@@ -36,12 +37,13 @@ def run(verbose: bool = True, smoke: bool = False) -> list:
         g = jnp.asarray(rng.standard_normal((k, d)), dt)
         b = jnp.asarray(rng.standard_normal((1, k)), dt)
         a = jnp.asarray(rng.standard_normal(k), dt)
-        a1 = jnp.asarray(rng.standard_normal(1), dt)
+        row = jnp.asarray(rng.standard_normal(k), jnp.float32)
+        a1 = jnp.float32(rng.standard_normal())
         nbytes = g.size * g.dtype.itemsize
         for name, t in (
             ("gc_encode", _bench(ref.encode_ref, b, g)),
             ("gc_decode", _bench(ref.decode_ref, a, g)),
-            ("gc_fused", _bench(ref.encode_decode_ref, a1, b, g)),
+            ("gc_fused", _bench(ref.encode_decode_ref, a1, row, g)),
         ):
             rows.append({"kernel": name, "k": k, "d": d,
                          "dtype": str(dt.__name__), "us": t * 1e6,

@@ -37,9 +37,9 @@ python -m repro.lint src tests benchmarks
 
 # tier-1 passed-count baseline.  Bump this when tests are added — it is
 # what catches silently lost/uncollected files, not just failures.  The
-# 54 v5e compile tests (tests/test_tpu_compile.py) skip without libtpu,
+# 58 v5e compile tests (tests/test_tpu_compile.py) skip without libtpu,
 # which requirements.txt brings through jax[tpu].
-BASELINE=537
+BASELINE=591
 # tests carrying @pytest.mark.spmd (registered in pytest.ini): the
 # multi-device subprocess tests the fast lane deselects.
 SPMD_COUNT=9

@@ -44,14 +44,13 @@ def decode(a: jax.Array, c: jax.Array, *, tile_d: int = 512,
     return ref._decode_math(a, c)
 
 
-def encode_decode(a: jax.Array, b_code: jax.Array, g: jax.Array, *,
-                  tile_d: int = 512, force_pallas: bool = False) -> jax.Array:
-    """Fused coded combine y = (a ⊙ B_code) @ G — encode and decode
-    weight folded into one streaming pass.  a: (NB,), b_code: (NB, K),
-    g: (K, D) -> (NB, D)."""
+def encode_decode(a: jax.Array, row: jax.Array, g: jax.Array, *,
+                  force_pallas: bool = False) -> jax.Array:
+    """Fused coded combine y = Σ_k a · row[k] · g[k] — encode and decode
+    weight folded into one streaming pass over the per-shard stack.
+    a: scalar or (1,), row: (K,), g: (K, *shape) -> (*shape)."""
     if on_tpu():
-        return encode_decode_pallas(a, b_code, g, tile_d=tile_d)
+        return encode_decode_pallas(a, row, g)
     if force_pallas:
-        return encode_decode_pallas(a, b_code, g, tile_d=tile_d,
-                                    interpret=True)
-    return ref._encode_decode_math(a, b_code, g)
+        return encode_decode_pallas(a, row, g, interpret=True)
+    return ref._encode_decode_math(a, row, g)

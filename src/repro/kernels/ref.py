@@ -26,13 +26,14 @@ def _decode_math(a: jax.Array, c: jax.Array) -> jax.Array:
     )[0].astype(c.dtype)
 
 
-def _encode_decode_math(a: jax.Array, b_code: jax.Array,
+def _encode_decode_math(a: jax.Array, row: jax.Array,
                         g: jax.Array) -> jax.Array:
-    """y = (a ⊙ B_code) @ G — encode and decode weight in one matmul."""
-    w = (a[:, None] * b_code).astype(g.dtype)
-    return jax.lax.dot_general(
-        w, g, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-    ).astype(g.dtype)
+    """y = Σ_k a · row[k] · g[k] — encode and decode weight in one
+    weighted sum over the (K, *shape) stack, float32 accumulation."""
+    f32 = jnp.float32
+    coef = jnp.reshape(a, ()).astype(f32) * row.astype(f32)
+    return jnp.tensordot(coef, g.astype(f32), axes=1,
+                         precision=jax.lax.Precision.HIGHEST).astype(g.dtype)
 
 
 encode_ref = jax.jit(_encode_math)

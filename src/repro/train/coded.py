@@ -11,7 +11,7 @@ is the jax integration:
 
       - ``pipeline='flat'`` (default when the plan carries a
         ``FlatLayout``): the FUSED path.  Per leaf, encode row and
-        decode weight fold into ONE skinny matmul (kernels/gc_fused
+        decode weight fold into ONE weighted sum (kernels/gc_fused
         math — a single streaming pass over the per-shard gradients,
         no separate scale pass, no per-leaf reduction bookkeeping).
         In spmd mode each rank's weighted contributions land in the
@@ -52,7 +52,7 @@ Each layer of the step runs under a ``jax.named_scope``, which lands in
 the compiled HLO's ``op_name`` metadata and so names the layer of every
 device op in a profiler trace (docs/PERF.md, "Profiling a run"):
 ``per_shard_grad`` (the K forward/backward passes), ``gc_pack`` (the
-stacks relaid out for the kernel and packed into the level buffers),
+combined leaves packed into the level buffers),
 ``gc_combine`` (the encode⊙decode kernel), ``level_collective`` (the
 per-level reduction) and ``gc_unpack`` (level buffers back to leaves).
 Scopes are metadata only: no computed value depends on them.
@@ -213,7 +213,7 @@ def _tree_scale(tree, dec_w_rank, level_idx):
 def _fused_level_leaves(layout, leaves_nk, b_rows, dec_w_row, li, n_workers,
                         grad_dtype):
     """Fused combine of ONE redundancy level's leaves: per leaf, the
-    skinny ``(dec_w ⊙ rows / N) @ G`` matmul over the (N*K, size)
+    weighted sum ``Σ (dec_w ⊙ rows / N) · g`` over the (N*K, *shape)
     shard-gradient stack — encode, decode weight, worker sum, and the
     1/N mean in a single streaming pass.
 
@@ -223,15 +223,14 @@ def _fused_level_leaves(layout, leaves_nk, b_rows, dec_w_row, li, n_workers,
     ``dec_w_row`` is that level's (N,) decode-weight row.  Returns
     ``{leaf_id: decoded mean grad}`` for the level's leaves.
     """
-    inv_n = jnp.ones((1,), jnp.float32) / n_workers
-    w = (dec_w_row[:, None] * b_rows[:, li, :]).reshape(1, -1)      # (1, N*K)
+    inv_n = jnp.float32(1.0 / n_workers)
+    w = (dec_w_row[:, None] * b_rows[:, li, :]).reshape(-1)         # (N*K,)
     out = {}
     for j in layout.level_leaves[li]:
-        shape = layout.leaf_shapes[j]
-        g = leaves_nk[j].reshape((w.shape[1], -1))                  # (N*K, sz)
+        shape = tuple(layout.leaf_shapes[j])
+        g = leaves_nk[j].reshape(w.shape + shape)              # (N*K, *shape)
         with jax.named_scope("gc_combine"):
-            y = ops.encode_decode(inv_n, w, g)[0]
-        y = y.reshape(shape)
+            y = ops.encode_decode(inv_n, w, g)
         if grad_dtype is not None:
             y = y.astype(grad_dtype)
         out[j] = y
@@ -283,26 +282,27 @@ def _fused_rank_levels(layout, leaves_k, rows_rank, dec_w_rank, denom,
     plan's per-level flat buffers (the collective's data structure).
 
     leaves_k: flat-order leaves shaped (K, *shape) — this rank's
-    per-shard grads.  Per leaf, the fused matmul streams the (K, size)
-    stack once; the results are laid out at the layout's static offsets
-    (lane-aligned, N-divisible zero tail), ready for one psum /
-    psum_scatter per level.  bf16 ``grad_dtype`` is applied to the
-    packed buffer, halving the collective bytes.
+    per-shard grads.  Per leaf, the fused kernel streams the (K, *shape)
+    stack once, in the layout the backward wrote it; the results are
+    laid out at the layout's static offsets (lane-aligned, N-divisible
+    zero tail), ready for one psum / psum_scatter per level.  bf16
+    ``grad_dtype`` is applied to the packed buffer, halving the
+    collective bytes.
 
-    Named scopes: ``gc_combine`` is the kernel call alone; the relayout
-    of each stack into the kernel's (K, size) operand, the zero tail,
-    the concatenation and the cast are ``gc_pack``.
+    Named scopes: ``gc_combine`` is the kernel call alone; the flatten
+    of each result, the zero tail, the concatenation and the cast are
+    ``gc_pack``.
     """
     bufs = []
     for li in range(layout.n_levels):
-        a = (dec_w_rank[li] / denom)[None]   # (1,) decode weight, mean folded
-        row = rows_rank[li][None, :]         # (1, K) coding row
+        a = dec_w_rank[li] / denom           # decode weight, mean folded
+        row = rows_rank[li]                  # (K,) coding row
         parts = []
         for j in layout.level_leaves[li]:
-            with jax.named_scope("gc_pack"):
-                g = leaves_k[j].reshape((row.shape[1], -1))  # (K, size)
             with jax.named_scope("gc_combine"):
-                parts.append(ops.encode_decode(a, row, g)[0])
+                y = ops.encode_decode(a, row, leaves_k[j])
+            with jax.named_scope("gc_pack"):
+                parts.append(y.reshape(-1))
         with jax.named_scope("gc_pack"):
             pad = layout.level_sizes[li] - layout.level_used[li]
             if pad:

@@ -6,10 +6,13 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.configs import get_config
 from repro.core import decode_weights, make_code
 from repro.kernels import ops, ref
 from repro.kernels.gc_decode import decode_pallas
 from repro.kernels.gc_encode import encode_pallas
+from repro.kernels.gc_fused import block_bytes, fused_block, stack_view
+from repro.train.state import abstract_train_state
 
 SHAPES = [(2, 128), (3, 1000), (5, 4096), (8, 513), (4, 131), (16, 2048)]
 DTYPES = [jnp.float32, jnp.bfloat16]
@@ -78,3 +81,30 @@ def test_kernel_level_decode_of_encode_property(n, data):
     a = decode_weights(b_mat, fastest)
     y = decode_pallas(jnp.asarray(a, jnp.float32), coded, tile_d=128, interpret=True)
     np.testing.assert_allclose(np.asarray(y), g.sum(axis=0), rtol=1e-4, atol=1e-4)
+
+
+SCOPED_VMEM_DEFAULT = 16 << 20  # Mosaic's default scoped VMEM limit, v5e
+
+
+@pytest.mark.parametrize("itemsize", [4, 2], ids=["fp32", "bf16"])
+@pytest.mark.parametrize("k", [1, 4, 8])
+@pytest.mark.parametrize("arch,n_leaves", [("gc-lm-110m", 11),
+                                           ("whisper-base", 103)])
+def test_fused_block_fits_scoped_vmem(arch, n_leaves, k, itemsize):
+    """Every leaf of both benchmark models: the fused kernel's block of
+    its (K, *shape) stack and the output block, double-buffered, fit
+    Mosaic's default scoped VMEM, and each tiled block dim is whole or a
+    multiple of its tile."""
+    params = abstract_train_state(get_config(arch))[0].params
+    leaves = [tuple(a.shape) for a in jax.tree.leaves(params)]
+    assert len(leaves) == n_leaves
+    sub = 8 * 4 // itemsize
+    for leaf in leaves:
+        view, k_axis = stack_view(k, leaf)
+        block = fused_block(view, k_axis, itemsize)
+        out = tuple(b for d, b in enumerate(block) if d != k_axis)
+        out_view = tuple(v for d, v in enumerate(view) if d != k_axis)
+        vmem = 2 * (block_bytes(block, itemsize) + block_bytes(out, itemsize))
+        assert vmem < SCOPED_VMEM_DEFAULT, (leaf, block, vmem)
+        assert out[-1] == out_view[-1] or out[-1] % 128 == 0, (leaf, out)
+        assert out[-2] == out_view[-2] or out[-2] % sub == 0, (leaf, out)
