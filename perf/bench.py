@@ -37,7 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from perf import check, flops, peaks
+from perf import check, flops, peaks, scopes, trace_reduce
 from perf.spec import ROOT, Cell
 from perf.traffic import ShardTokens, frame_ids, make_frame_pool
 from perf.weights import make_params
@@ -345,6 +345,55 @@ def _trace_window(prog, step_fn, seconds, trace_dir: Path):
     return times, bad, traced_s
 
 
+def layer_record(cell: Cell, ev: dict, hlo: str, *, k_shards: int,
+                 ranks: int, chips: int, kind: str, steps: int,
+                 window_s: float, traced_s: float, memory_peak_bytes: int,
+                 compiled_bytes: Optional[int]) -> dict:
+    """What each per-layer metric's reader gets, from a traced window's
+    events (``trace_reduce.events_of``) and the step's HLO text.
+
+    The cell's ``config`` and ``traffic`` files as loaded, ``k_shards``
+    and ``ranks`` (per process), ``chips``, the device's ``peak``;
+    ``step_flops`` (``flops.step_flops``, by the configuration's own
+    ``forward_flops`` where its reference defines one) and
+    ``combine_bytes``; the window's host readings as given; ``trace``,
+    ``trace_reduce.reduce_events``' structural classes; ``scopes``,
+    ``scope_s``, ``unscoped_s`` and ``mixed_s`` of
+    ``scopes.scope_times``; and ``ops``, every op of the step that ran,
+    ``[name, class, op_name, seconds per chip]``, which
+    ``scopes.part_s`` sums by any component of the ``op_name`` path.
+    All times are per chip over the whole window.
+    """
+    sc = scopes.scope_times(ev, hlo, chips, SPANS)
+    return {
+        "cell": cell.name, "config": cell.config, "traffic": cell.traffic,
+        "k_shards": k_shards, "ranks": ranks, "chips": chips,
+        "peak": peaks.peak(kind),
+        "step_flops": flops.step_flops(cell.config["model"], cell.traffic,
+                                       k_shards, ranks, cell.reference),
+        "combine_bytes": flops.combine_bytes(cell.config["params"],
+                                             k_shards, ranks),
+        "steps": steps, "window_s": window_s, "traced_s": traced_s,
+        "memory_peak_bytes": memory_peak_bytes,
+        "compiled_bytes": compiled_bytes,
+        "trace": trace_reduce.reduce_events(ev, hlo, chips, SPANS),
+        "scopes": {k: sc[k] for k in ("scope_s", "unscoped_s", "mixed_s")},
+        "ops": sc["ops"],
+    }
+
+
+def layer_metrics(cell: Cell, record: dict) -> dict:
+    """The cell's per-layer metrics, each by its own reader
+    (``perf/metrics/<name>.py``); one whose reader finds nothing to read
+    is left out."""
+    metrics = {}
+    for m in cell.per_layer:
+        value = cell.metric_reader(m["name"])(record)
+        if value is not None:
+            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    return metrics
+
+
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
              t_start: float, require_tpu: bool = True,
              step_hook: Optional[Callable] = None,
@@ -354,8 +403,6 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     ``step_hook`` wraps the compiled step (tests plant faults with it);
     ``dump`` keeps the raw trace and the step's HLO text there.
     """
-    from perf import trace_reduce
-
     if require_tpu:
         devices = require_chips(cell.chips)
     else:
@@ -384,10 +431,6 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     steps = len(times)
     window_s = float(sum(times))
     tokens = prog.useful_tokens_per_step()
-    step_flops = flops.step_flops(cell.config["model"], cell.traffic,
-                                  prog.k, prog.ranks)
-    combine_bytes = flops.combine_bytes(cell.config["params"], prog.k,
-                                        prog.ranks)
     kind = devices[0].device_kind
     platform = devices[0].platform
     del prog.state, step_fn
@@ -417,28 +460,19 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
                              for m in cell.end_to_end}
     else:
         trace_dir = TRACE_DIR / cell.name
-        reduced = trace_reduce.reduce_dir(trace_dir, hlo, n_chips=len(devices),
-                                          spans=SPANS)
+        ev = trace_reduce.load_events(trace_dir, SPANS)
         if dump is not None:
             dump.mkdir(parents=True, exist_ok=True)
             (dump / f"{cell.name}.hlo.txt").write_text(hlo)
             shutil.copytree(trace_dir, dump / f"{cell.name}.trace",
                             dirs_exist_ok=True)
         shutil.rmtree(trace_dir, ignore_errors=True)
-        record = {
-            "cell": cell.name, "steps": steps, "window_s": window_s,
-            "traced_s": traced_s, "chips": len(devices),
-            "peak": peaks.peak(kind), "step_flops": step_flops,
-            "combine_bytes": combine_bytes, "memory_peak_bytes": peak,
-            "compiled_bytes": held,
-            "trace": reduced,
-        }
-        metrics = {}
-        for m in cell.per_layer:
-            value = cell.metric_reader(m["name"])(record)
-            if value is not None:
-                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-        result["metrics"] = metrics
+        record = layer_record(
+            cell, ev, hlo, k_shards=prog.k, ranks=prog.ranks,
+            chips=len(devices), kind=kind, steps=steps, window_s=window_s,
+            traced_s=traced_s, memory_peak_bytes=peak, compiled_bytes=held)
+        result["metrics"] = layer_metrics(cell, record)
+        reduced = record["trace"]
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
         result["breakdown"] = {"device_ops": reduced["top_ops"],

@@ -5,7 +5,10 @@ multiply-add of every matrix product (projections, MLP, the tied
 logits), attention's two products (scores and the weighted sum), a
 causal product counted at half, and training as three forward passes
 (forward, and the backward's two products per forward product).  Norms,
-softmax and the embedding gather are left out.
+softmax and the embedding gather are left out.  ``forward_flops`` is the
+formula of a dense decoder (and encoder-decoder) with MHA/GQA attention
+and a plain or gated MLP; a configuration of another shape (latent
+attention, routed experts) brings its own count in its reference module.
 """
 from __future__ import annotations
 
@@ -39,14 +42,19 @@ def forward_flops(m: dict, seq_len: int) -> int:
     return int(total)
 
 
-def step_flops(m: dict, traffic: dict, k_shards: int, ranks: int) -> dict:
+def step_flops(m: dict, traffic: dict, k_shards: int, ranks: int,
+               reference=None) -> dict:
     """Per-step FLOPs of the ranks a process holds.
 
     ``useful``: one unique shard per rank (what a user's step computes);
     ``backward``: each rank's K per-shard forward+backward passes, the
-    coded redundancy included.
+    coded redundancy included.  A forward pass is priced by the
+    configuration's own ``forward_flops(model, seq_len)`` where its
+    reference module (``perf/configs/<config>.py``) defines one, and by
+    the dense formula here otherwise.
     """
-    shard = 3 * traffic["rows_per_shard"] * forward_flops(m, traffic["seq_len"])
+    forward = getattr(reference, "forward_flops", forward_flops)
+    shard = 3 * traffic["rows_per_shard"] * forward(m, traffic["seq_len"])
     return {"useful": ranks * shard, "backward": ranks * k_shards * shard}
 
 

@@ -17,6 +17,11 @@ counts in ``mixed_s``.  An instruction with no scope in its ``op_name``
 ``unscoped_s``.  A trace of a program that has no scopes is all
 ``unscoped_s``.
 
+``op_times`` lists each op with its ``op_name`` path, and ``part_s``
+sums the ops under any component of that path: a scope the program
+nests inside a layer (an expert layer under ``per_shard_grad``) is timed
+by name, without an entry in ``SCOPES``.
+
     python3 -m perf.scopes --dump <dir> --cell <name>
 
 reduces what ``python3 -m perf.run --workload <name> ... --trace 1 --dump
@@ -72,10 +77,6 @@ class ScopedHlo(trace_reduce.Hlo):
                         for s in found} - {UNSCOPED}
         self._mixed = {}
 
-    def scope(self, name: str) -> str:
-        info = self.instr.get(name)
-        return scope_of(info[1]) if info else UNSCOPED
-
     def mixed(self, name: str) -> bool:
         """Whether the fusion ``name`` fuses instructions of two scopes."""
         if name not in self._mixed:
@@ -92,53 +93,79 @@ class ScopedHlo(trace_reduce.Hlo):
         return self._mixed[name]
 
 
+def op_times(ev: dict, hlo: ScopedHlo, n_chips: int, spans: tuple) -> list:
+    """Every non-control instruction of the step program that ran in the
+    window, as ``[name, class, op_name, seconds per chip]``, the longest
+    first.  The window and classes are ``trace_reduce.reduce_events``'
+    (see ``events_of``); the programs of the feed are left out."""
+    lo, hi = trace_reduce.window_of(ev["host"], spans)
+    planes = trace_reduce.planes_of(ev, n_chips)
+    total = {}
+    for plane in planes:
+        for name, s, e, mod in ev["devices"][plane]:
+            s, e = max(s, lo), min(e, hi)
+            if e > s and mod == hlo.module:
+                total[name] = total.get(name, 0.0) + (e - s)
+    out = []
+    for name, sec in total.items():
+        cls = hlo.classify(name)
+        if cls not in ("feed", "control"):
+            info = hlo.instr.get(name)
+            out.append([name, cls, info[1] if info else "", sec / len(planes)])
+    return sorted(out, key=lambda op: (-op[3], op[0]))
+
+
 def scope_times(ev: dict, hlo_text: str, n_chips: int, spans: tuple) -> dict:
     """Per-chip seconds of the step program's device ops by scope, over
     ``trace_reduce.reduce_events``' window and ops (see ``events_of``).
 
-    ``scope_s`` holds every scope the HLO carries (0.0 where none of its
-    ops ran on its own); ``scope_s`` plus ``unscoped_s`` is the step
-    program's non-control op time.  ``mixed_s`` is the part of it spent
-    in fusions of two scopes; ``cross_s`` splits it by structural class
-    and scope; ``top_unscoped`` names the largest unscoped ops.
+    ``ops`` is ``op_times``.  ``scope_s`` holds every scope the HLO
+    carries (0.0 where none of its ops ran on its own); ``scope_s`` plus
+    ``unscoped_s`` is the step program's non-control op time.
+    ``mixed_s`` is the part of it spent in fusions of two scopes;
+    ``cross_s`` splits it by structural class and scope;
+    ``top_unscoped`` names the largest unscoped ops.
     """
     hlo = ScopedHlo(hlo_text)
-    host = ev["host"]
-    if not host:
-        raise ValueError("no host spans in the trace")
-    lo = min(s for n, s, e in host if n == spans[0])
-    hi = max(e for n, s, e in host if n == spans[-1])
-    planes = sorted(ev["devices"])[:n_chips]
-    if len(planes) < n_chips:
-        raise ValueError(f"trace has {len(planes)} device planes, the cell "
-                         f"uses {n_chips}")
+    ops = op_times(ev, hlo, n_chips, spans)
     per_scope = dict.fromkeys(sorted(hlo.present, key=SCOPES.index), 0.0)
-    cross, unscoped, mixed = {}, {}, 0.0
-    for plane in planes:
-        for name, s, e, mod in ev["devices"][plane]:
-            s, e = max(s, lo), min(e, hi)
-            cls = hlo.classify(name) if mod == hlo.module else "feed"
-            if e <= s or cls in ("feed", "control"):
-                continue
-            scope = hlo.scope(name)
-            if scope == UNSCOPED:
-                unscoped[name] = unscoped.get(name, 0.0) + (e - s)
-            else:
-                per_scope[scope] += e - s
-            row = cross.setdefault(cls, {})
-            row[scope] = row.get(scope, 0.0) + (e - s)
-            if hlo.mixed(name):
-                mixed += e - s
-    k = float(len(planes))
-    top = sorted(unscoped.items(), key=lambda kv: -kv[1])[:10]
+    cross, unscoped, mixed = {}, [], 0.0
+    for name, cls, op_name, sec in ops:
+        scope = scope_of(op_name)
+        if scope == UNSCOPED:
+            unscoped.append([name, sec])
+        else:
+            per_scope[scope] += sec
+        row = cross.setdefault(cls, {})
+        row[scope] = row.get(scope, 0.0) + sec
+        if hlo.mixed(name):
+            mixed += sec
     return {
-        "scope_s": {sc: v / k for sc, v in per_scope.items()},
-        "unscoped_s": sum(unscoped.values()) / k,
-        "mixed_s": mixed / k,
-        "cross_s": {c: {sc: v / k for sc, v in row.items()}
-                    for c, row in cross.items()},
-        "top_unscoped": [[n, v / k] for n, v in top],
+        "scope_s": per_scope,
+        "unscoped_s": sum(sec for _, sec in unscoped),
+        "mixed_s": mixed,
+        "cross_s": cross,
+        "top_unscoped": unscoped[:10],
+        "ops": ops,
     }
+
+
+def part_s(rec: dict, name: str):
+    """Per-chip seconds of the ops in ``rec["ops"]`` whose ``op_name``
+    path has ``name`` as a component, wherever it is nested (a scope
+    inside ``per_shard_grad`` counts here and in ``per_shard_grad``'s
+    ``scope_s``); None where no op that ran carries it."""
+    secs = [op[3] for op in rec["ops"] if name in op[2].split("/")]
+    return sum(secs) if secs else None
+
+
+def per_step_ms(rec: dict, *names: str):
+    """Milliseconds per step and chip of the scopes ``names`` together,
+    from ``rec["scopes"]["scope_s"]``; None where none of them took
+    time."""
+    sec = sum(rec["scopes"]["scope_s"].get(n, 0.0) for n in names)
+    steps = rec["trace"]["steps"]
+    return 1e3 * sec / steps if sec > 0 and steps else None
 
 
 def module_runs(pd, module: str) -> dict:

@@ -11,6 +11,27 @@ configuration and a traffic mix; each lives in files of its own:
 
 Adding a configuration, cell or metric adds files and entries; no file
 here changes.  ``base`` lets a test point the lookup at another tree.
+
+What a new configuration may bring besides its sizes and reference:
+
+  * ``forward_flops(model, seq_len)`` in its reference module: FLOPs of
+    one forward pass of one row, which ``perf/flops.py:step_flops`` then
+    uses in place of its dense formula (for ``mfu``, ``backward_roofline``
+    and any reader of ``rec["step_flops"]``);
+  * readers of its own layers.  A reader gets the record that
+    ``perf/bench.py:layer_record`` builds: ``rec["config"]`` and
+    ``rec["traffic"]`` (its files as loaded), ``k_shards``, ``ranks``,
+    ``chips``, ``peak``; ``rec["trace"]``, the structural classes;
+    ``rec["scopes"]``, time by the program's named scopes; and
+    ``rec["ops"]``, every op of the step as ``[name, class, op_name,
+    seconds per chip]``.  ``perf/scopes.py:part_s(rec, name)`` times any
+    scope the program adds, nested where it may be (an expert layer
+    under ``per_shard_grad``); a reader returns None where it finds
+    nothing to read.
+
+A Pallas kernel (``tpu_custom_call``) inside the per-shard backward is
+classed ``backward``; only one outside it is the ``combine``
+(``perf/trace_reduce.py:Hlo.classify``).
 """
 from __future__ import annotations
 

@@ -1,6 +1,6 @@
 """``perf/flops.py`` against counts made by hand from the published sizes."""
 from perf import flops
-from perf.spec import PERF_DIR, load_json
+from perf.spec import PERF_DIR, load_json, load_module
 
 GCLM = load_json(PERF_DIR / "configs" / "gc-lm-110m.json")["model"]
 WHISPER = load_json(PERF_DIR / "configs" / "whisper-base-fp32.json")["model"]
@@ -47,3 +47,27 @@ def test_step_flops_count_useful_and_redundant_work():
 def test_combine_bytes_read_the_stack_once_and_write_once():
     assert flops.combine_bytes(137_841_408, 8, 1) == 9 * 4 * 137_841_408
     assert flops.combine_bytes(100, 1, 4) == 4 * 2 * 4 * 100
+
+
+def test_a_configuration_brings_its_own_forward_count():
+    from types import SimpleNamespace
+
+    own = SimpleNamespace(forward_flops=lambda m, s: 1000 * s + m["vocab"])
+    traffic = {"rows_per_shard": 2, "seq_len": 16}
+    got = flops.step_flops(GCLM, traffic, k_shards=4, ranks=1,
+                           reference=own)
+    shard = 3 * 2 * (1000 * 16 + 32000)
+    assert got == {"useful": shard, "backward": 4 * shard}
+
+
+def test_the_two_configurations_keep_the_dense_hand_counts():
+    # neither reference module defines forward_flops: the formula prices
+    # both, at the totals counted by hand above
+    for name, model, seq, per_row in (
+            ("gc-lm-110m", GCLM, 1024, 301_587_234_816),
+            ("whisper-base-fp32", WHISPER, 448, 146_722_127_872)):
+        ref = load_module(PERF_DIR / "configs" / f"{name}.py")
+        assert not hasattr(ref, "forward_flops")
+        got = flops.step_flops(model, {"rows_per_shard": 1, "seq_len": seq},
+                               k_shards=1, ranks=1, reference=ref)
+        assert got == {"useful": 3 * per_row, "backward": 3 * per_row}

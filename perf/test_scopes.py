@@ -156,3 +156,85 @@ def test_module_runs_reads_the_step_modules_of_each_chip():
     assert list(runs) == ["/device:TPU:0"]
     assert runs["/device:TPU:0"] == [pytest.approx((200e-9, 250e-9)),
                                      pytest.approx((300e-9, 350e-9))]
+
+
+#: a part of the model (an expert layer, say) under its own scope inside
+#: the per-shard backward, beside the same scope name outside it
+HLO_NESTED = """HloModule jit_step, entry_computation_layout={()}
+
+%body (t: (f32[8])) -> (f32[8]) {
+  %fusion.1 = f32[8]{0} fusion(f32[8]{0} %p), kind=kLoop, metadata={op_name="jit(step)/per_shard_grad/while/body/jvp(jit(train_loss))/toy_part/dot_general"}
+  %custom-call.2 = f32[8]{0} custom-call(f32[8]{0} %p), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/per_shard_grad/while/body/transpose(jvp(jit(train_loss)))/toy_part/pallas_call"}
+  %fusion.3 = f32[8]{0} fusion(f32[8]{0} %p), kind=kLoop, metadata={op_name="jit(step)/per_shard_grad/while/body/jvp(jit(train_loss))/toy_partner/mul"}
+}
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %while.4 = (f32[8]{0}) while((f32[8]{0}) %t), condition=%cond, body=%body, metadata={op_name="jit(step)/per_shard_grad/while"}
+  %custom-call.5 = f32[8]{0} custom-call(f32[8]{0} %a), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/gc_combine/pallas_call"}
+  %fusion.6 = f32[8]{0} fusion(f32[8]{0} %c), kind=kLoop, metadata={op_name="jit(step)/monitor_forward/toy_part/dot_general"}
+  ROOT %fusion.7 = f32[8]{0} fusion(f32[8]{0} %c), kind=kLoop, metadata={op_name="jit(step)/optimizer/mul"}
+}
+"""
+
+NESTED_OPS = [("while.4", 0.20, 0.50), ("fusion.1", 0.20, 0.30),
+              ("custom-call.2", 0.30, 0.45), ("fusion.3", 0.45, 0.50),
+              ("custom-call.5", 0.50, 0.60), ("fusion.6", 0.60, 0.64),
+              ("fusion.7", 0.64, 0.70)]
+
+
+def _nested_record(n_steps=2):
+    ev = _events(n_steps)
+    ev["devices"]["/device:TPU:0"] = [
+        (n, t0 + s, t0 + e, "jit_step") for t0 in map(float, range(n_steps))
+        for n, s, e in NESTED_OPS]
+    sc = S.scope_times(ev, HLO_NESTED, 1, SPANS)
+    return {"ops": sc["ops"], "scopes": sc,
+            "trace": T.reduce_events(ev, HLO_NESTED, 1, SPANS)}
+
+
+def test_ops_list_every_step_op_with_its_class_and_path():
+    rec = _nested_record()
+    ops = {name: (cls, op_name, sec) for name, cls, op_name, sec in rec["ops"]}
+    assert set(ops) == {"fusion.1", "custom-call.2", "fusion.3",
+                        "custom-call.5", "fusion.6", "fusion.7"}
+    assert ops["custom-call.2"][0] == "backward"   # a kernel in the loop
+    assert ops["custom-call.5"][0] == "combine"
+    assert ops["fusion.6"][1] == "jit(step)/monitor_forward/toy_part/dot_general"
+    assert ops["custom-call.2"][2] == pytest.approx(2 * 0.15)
+    assert [op[0] for op in rec["ops"]][0] == "custom-call.2"   # longest
+
+
+def test_part_s_times_a_scope_nested_in_another():
+    rec = _nested_record()
+    # toy_part inside the backward and inside the monitoring forward; not
+    # toy_partner, whose name only begins the same
+    assert S.part_s(rec, "toy_part") == pytest.approx(2 * (0.10 + 0.15
+                                                           + 0.04))
+    assert S.part_s(rec, "toy_partner") == pytest.approx(2 * 0.05)
+    assert S.part_s(rec, "per_shard_grad") == pytest.approx(2 * 0.30)
+    assert S.part_s(rec, "no_such_part") is None
+    # the first scope of each path still takes the op whole
+    scope_s = rec["scopes"]["scope_s"]
+    assert scope_s["per_shard_grad"] == pytest.approx(2 * 0.30)
+    assert scope_s["monitor_forward"] == pytest.approx(2 * 0.04)
+
+
+def test_per_step_ms_sums_scopes_and_is_none_where_absent():
+    rec = _nested_record()
+    assert S.per_step_ms(rec, "optimizer") == pytest.approx(1e3 * 0.06)
+    assert S.per_step_ms(rec, "optimizer", "monitor_forward") == \
+        pytest.approx(1e3 * 0.10)
+    assert S.per_step_ms(rec, "gc_pack", "gc_unpack") is None
+    rec["trace"]["steps"] = 0
+    assert S.per_step_ms(rec, "optimizer") is None
+
+
+@pytest.mark.parametrize("metric, want", [
+    ("optimizer_ms", 1e3 * 0.06), ("monitor_forward_ms", 1e3 * 0.04),
+    ("pack_unpack_ms", None)])
+def test_scope_metric_readers(metric, want):
+    from perf.spec import load_module
+
+    read = load_module(PERF_DIR / "metrics" / f"{metric}.py").read
+    got = read(_nested_record())
+    assert got is None if want is None else got == pytest.approx(want)

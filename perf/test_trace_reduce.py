@@ -112,3 +112,75 @@ def test_reduce_a_trace_recorded_on_the_chip():
     ops = blob["events"]["devices"]["/device:TPU:0"]
     step_ops = [name for name, _, _, mod in ops if mod == hlo.module]
     assert step_ops and all(name in hlo.instr for name in step_ops)
+
+
+#: a Pallas kernel inside the per-shard loop (an attention or expert
+#: kernel of the model) beside the combine's kernel outside it
+HLO_KERNELS = """HloModule jit_step, entry_computation_layout={()}
+
+%body (t: (f32[8])) -> (f32[8]) {
+  %fusion.1 = f32[8]{0} fusion(f32[8]{0} %p), kind=kLoop, metadata={op_name="jit(step)/per_shard_grad/while/body/jvp(jit(train_loss))/dot_general"}
+  %custom-call.2 = f32[8]{0} custom-call(f32[8]{0} %fusion.1), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/per_shard_grad/while/body/transpose(jvp(jit(train_loss)))/expert_gmm/pallas_call"}
+}
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %while.3 = (f32[8]{0}) while((f32[8]{0}) %t), condition=%cond, body=%body
+  %custom-call.4 = f32[1,512]{1,0} custom-call(f32[8]{0} %a), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/gc_combine/pallas_call"}
+  %custom-call.5 = f32[8]{0} custom-call(f32[8]{0} %a), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp(jit(train_loss))/attention/pallas_call"}
+  ROOT %custom-call.6 = f32[8]{0} custom-call(f32[8]{0} %a), custom_call_target="Sharding"
+}
+"""
+
+
+def test_a_custom_call_in_the_backward_is_backward():
+    hlo = T.Hlo(HLO_KERNELS)
+    # inside the per-shard loop's body, or under jvp( where XLA unrolled
+    # the loop (K = 1): backward; outside both: the combine
+    assert hlo.classify("custom-call.2") == "backward"
+    assert hlo.classify("custom-call.5") == "backward"
+    assert hlo.classify("custom-call.4") == "combine"
+    assert hlo.classify("custom-call.6") == "other"
+
+
+def test_the_chip_fixture_keeps_its_classes():
+    """Every Pallas kernel of the recorded step lies outside the per-shard
+    loop, so classing a custom call in the backward as backward moves
+    none of its time."""
+    with gzip.open(FIXTURE, "rt") as f:
+        blob = json.load(f)
+    hlo = T.Hlo(blob["hlo"])
+    kernels = [n for n, (op, _, tgt, _) in hlo.instr.items()
+               if op == "custom-call" and tgt == "tpu_custom_call"]
+    assert kernels and {hlo.classify(n) for n in kernels} == {"combine"}
+    out = T.reduce_events(blob["events"], blob["hlo"], blob["n_chips"],
+                          tuple(blob["spans"]))
+    want = blob["expected"]
+    assert out["class_s"] == want["class_s"]
+    assert [list(x) for x in out["top_ops"]] == want["top_ops"]
+    assert out["collective_exposed_s"] == want["collective_exposed_s"]
+
+
+def test_the_existing_metrics_read_the_fixture_as_before(tiny_cell):
+    """The eight metrics that read the structural classes give the same
+    values from ``bench.layer_record`` as from the reduction recorded
+    with the fixture, and the scope metrics find no scope in a trace of
+    a program that had none.  (The four-chip cell's list holds all
+    eight; the one-chip trace has no collective to read.)"""
+    from perf.bench import layer_metrics, layer_record
+
+    with gzip.open(FIXTURE, "rt") as f:
+        blob = json.load(f)
+    cell = tiny_cell("gclm-dp4-n4-xf")
+    rec = layer_record(cell, blob["events"], blob["hlo"], k_shards=8,
+                       ranks=1, chips=blob["n_chips"],
+                       kind=blob["device_kind"], steps=2,
+                       window_s=blob["expected"]["window_s"], traced_s=0.5,
+                       memory_peak_bytes=1 << 30, compiled_bytes=3 << 30)
+    before = dict(rec, trace=blob["expected"])
+    old = ["mfu", "backward_roofline", "combine_ms", "combine_roofline",
+           "collective_exposed_ms", "host_gap_ms", "device_idle_share",
+           "hbm_peak_gib"]
+    assert [m["name"] for m in cell.per_layer][:8] == old
+    got, want = layer_metrics(cell, rec), layer_metrics(cell, before)
+    assert got == want
+    assert sorted(got) == sorted(set(old) - {"collective_exposed_ms"})

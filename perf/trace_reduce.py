@@ -10,12 +10,14 @@ holds the benchmark's own spans (``jax.profiler.TraceAnnotation``).
 Each device event is classed by its instruction in the compiled step's
 HLO text (kept from the run), without touching the program:
 
-  * ``combine``: a Pallas kernel (``custom-call`` to ``tpu_custom_call``);
   * ``collective``: all-reduce, reduce-scatter, all-gather,
     collective-permute or all-to-all (their start/done halves too);
   * ``backward``: an instruction inside the per-shard loop (see ``Hlo``):
     the K shards' forward and backward passes and the stacking of their
-    gradients;
+    gradients, a Pallas kernel among them (an attention or expert kernel
+    of the model is backward, not combine);
+  * ``combine``: a Pallas kernel (``custom-call`` to ``tpu_custom_call``)
+    outside the per-shard loop;
   * ``other``: the rest of the step: optimizer, monitoring forward, and
     the packing into and unpacking from the level buffers;
   * ``feed``: instructions of other programs (the benchmark's input
@@ -112,12 +114,12 @@ class Hlo:
         opcode, op_name, target, comp = info
         if opcode in CONTROL:
             return "control"
-        if opcode == "custom-call" and target == "tpu_custom_call":
-            return "combine"
         if any(opcode.startswith(c) for c in COLLECTIVES):
             return "collective"
         if comp in self.backward_comps or "jvp(" in op_name:
             return "backward"
+        if opcode == "custom-call" and target == "tpu_custom_call":
+            return "combine"
         return "other"
 
 
@@ -199,24 +201,36 @@ def events_of(pd, spans: tuple) -> dict:
     return {"devices": devices, "host": host}
 
 
+def window_of(host: list, spans: tuple) -> tuple:
+    """(start, end) of the window: the first ``spans[0]`` span's start to
+    the last ``spans[-1]`` span's end."""
+    if not host:
+        raise ValueError("no host spans in the trace")
+    return (min(s for n, s, e in host if n == spans[0]),
+            max(e for n, s, e in host if n == spans[-1]))
+
+
+def planes_of(ev: dict, n_chips: int) -> list:
+    """The device planes of the cell's ``n_chips`` chips."""
+    planes = sorted(ev["devices"])[:n_chips]
+    if len(planes) < n_chips:
+        raise ValueError(f"trace has {len(planes)} device planes, the cell "
+                         f"uses {n_chips}")
+    return planes
+
+
 def reduce_events(ev: dict, hlo_text: str, n_chips: int,
                   spans: tuple) -> dict:
     """Per-step, per-chip numbers from plain events (see ``events_of``)."""
     hlo = Hlo(hlo_text)
     host = ev["host"]
-    if not host:
-        raise ValueError("no host spans in the trace")
-    lo = min(s for n, s, e in host if n == spans[0])
-    hi = max(e for n, s, e in host if n == spans[-1])
+    lo, hi = window_of(host, spans)
     steps = sum(1 for n, s, e in host if n == spans[0])
     span_iv = {name: union((s, e) for n, s, e in host if n == name)
                for name in spans}
     per_class, busy, exposed, idle_by_span = {}, 0.0, 0.0, {}
     op_time = {}
-    planes = sorted(ev["devices"])[:n_chips]
-    if len(planes) < n_chips:
-        raise ValueError(f"trace has {len(planes)} device planes, the cell "
-                         f"uses {n_chips}")
+    planes = planes_of(ev, n_chips)
     for plane in planes:
         ivs, coll, comp = [], [], []
         for name, s, e, mod in ev["devices"][plane]:
@@ -259,8 +273,8 @@ def reduce_events(ev: dict, hlo_text: str, n_chips: int,
     }
 
 
-def reduce_dir(trace_dir: Path, hlo_text: str, n_chips: int,
-               spans: tuple) -> dict:
+def load_events(trace_dir: Path, spans: tuple) -> dict:
+    """``events_of`` the one ``.xplane.pb`` under ``trace_dir``."""
     import jax
 
     files = glob.glob(str(Path(trace_dir) / "**" / "*.xplane.pb"),
@@ -268,7 +282,4 @@ def reduce_dir(trace_dir: Path, hlo_text: str, n_chips: int,
     if len(files) != 1:
         raise ValueError(f"expected one .xplane.pb under {trace_dir}, "
                          f"found {len(files)}")
-    pd = jax.profiler.ProfileData.from_file(files[0])
-    return reduce_events(events_of(pd, spans), hlo_text, n_chips, spans)
-
-
+    return events_of(jax.profiler.ProfileData.from_file(files[0]), spans)
